@@ -1,23 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-
-# ---- version-compat shim -------------------------------------------------
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (and will
-# eventually drop the old name).  The kernel modules in this package all
-# spell it ``pltpu.CompilerParams``; alias whichever name this jax build
-# is missing so both spellings resolve.  Kernel modules import the parent
-# package first, so the alias is in place before any call site runs.
-# Builds without pallas-tpu keep importing: only the pallas impls need it.
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:  # pragma: no cover - chunked/ref impls still work
-    _pltpu = None
-
-if _pltpu is not None:
-    if not hasattr(_pltpu, "CompilerParams") and hasattr(_pltpu, "TPUCompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-    elif not hasattr(_pltpu, "TPUCompilerParams") and hasattr(_pltpu, "CompilerParams"):
-        _pltpu.TPUCompilerParams = _pltpu.CompilerParams
-
-del _pltpu

@@ -77,11 +77,10 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            scale: Optional[float] = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: (B,T,H,D); k,v: (B,S,K,D).  Returns (B,T,H,D).
 
-    ``interpret=True`` (default here) runs the kernel body on CPU for
-    validation; production TPU runs pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body on the CPU for validation.
     """
     B, T, H, D = q.shape
     _, S, K, _ = k.shape

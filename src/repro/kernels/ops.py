@@ -4,7 +4,7 @@ Every op has (a) a memory-efficient pure-jnp implementation that lowers on
 any backend — this is what the multi-pod dry-run compiles — and (b) a
 Pallas TPU kernel (``impl="pallas"``) validated in interpret mode against
 :mod:`repro.kernels.ref`.  Production TPU deployments flip the impl flag;
-nothing else changes.
+``interpret=True`` runs the kernel body on the CPU instead of the chip.
 """
 
 from __future__ import annotations
@@ -26,7 +26,8 @@ _NEG_INF = -1e30
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     scale: Optional[float] = None, impl: str = "chunked",
-                    q_chunk: int = 512, kv_chunk: int = 1024) -> jax.Array:
+                    q_chunk: int = 512, kv_chunk: int = 1024,
+                    interpret: bool = False) -> jax.Array:
     """Memory-efficient attention.  q: (B,T,H,D); k,v: (B,S,K,D), H%K==0.
 
     The last query position is aligned with the last key position (so a
@@ -40,7 +41,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl == "pallas":
         from repro.kernels import flash_attention as _fa
         return _fa.flash_attention_pallas(q, k, v, causal=causal,
-                                          window=window, scale=scale)
+                                          window=window, scale=scale,
+                                          interpret=interpret)
     return _flash_chunked(q, k, v, causal, window, scale, q_chunk, kv_chunk)
 
 
@@ -151,8 +153,8 @@ def _assoc_combine(e1, e2):
 
 def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, D: jax.Array, h0: Optional[jax.Array] = None, *,
-             impl: str = "chunked", time_chunk: int = 16
-             ) -> Tuple[jax.Array, jax.Array]:
+             impl: str = "chunked", time_chunk: int = 16,
+             interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Mamba1 selective scan.  Shapes as :func:`repro.kernels.ref.ssm_scan_ref`.
 
     ``chunked``: sequential scan over time chunks, associative scan inside
@@ -162,7 +164,8 @@ def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         return _ref.ssm_scan_ref(x, dt, A, B, C, D, h0)
     if impl == "pallas":
         from repro.kernels import ssm_scan as _ss
-        return _ss.ssm_scan_pallas(x, dt, A, B, C, D, h0)
+        return _ss.ssm_scan_pallas(x, dt, A, B, C, D, h0,
+                                   interpret=interpret)
     Bt, T, I = x.shape
     N = A.shape[1]
     Tc = min(time_chunk, T)
@@ -214,8 +217,8 @@ def ssm_step(xt: jax.Array, dtt: jax.Array, A: jax.Array, Bt_: jax.Array,
 
 def rglru(x: jax.Array, a_gate: jax.Array, i_gate: jax.Array,
           log_lam: jax.Array, h0: Optional[jax.Array] = None, *,
-          c: float = 8.0, impl: str = "chunked", time_chunk: int = 256
-          ) -> Tuple[jax.Array, jax.Array]:
+          c: float = 8.0, impl: str = "chunked", time_chunk: int = 256,
+          interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """RG-LRU over a sequence.  Shapes as :func:`repro.kernels.ref.rglru_ref`.
 
     ``chunked`` (default): sequential scan over time chunks with the
@@ -227,7 +230,8 @@ def rglru(x: jax.Array, a_gate: jax.Array, i_gate: jax.Array,
         return _ref.rglru_ref(x, a_gate, i_gate, log_lam, h0, c=c)
     if impl == "pallas":
         from repro.kernels import rglru_scan as _rs
-        return _rs.rglru_pallas(x, a_gate, i_gate, log_lam, h0, c=c)
+        return _rs.rglru_pallas(x, a_gate, i_gate, log_lam, h0, c=c,
+                                interpret=interpret)
 
     def gates(xg, ag, ig, mask):
         lam = jax.nn.softplus(log_lam.astype(jnp.float32))
@@ -292,11 +296,11 @@ def rglru_step(xt: jax.Array, a_gate: jax.Array, i_gate: jax.Array,
 # ===========================================================================
 # int8 quantization (gradient compression)
 # ===========================================================================
-def quantize(x: jax.Array, *, impl: str = "jnp"
+def quantize(x: jax.Array, *, impl: str = "jnp", interpret: bool = False
              ) -> Tuple[jax.Array, jax.Array]:
     if impl == "pallas":
         from repro.kernels import quantize as _qz
-        return _qz.quantize_pallas(x)
+        return _qz.quantize_pallas(x, interpret=interpret)
     return _ref.quantize_ref(x)
 
 

@@ -25,7 +25,7 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 
 
 def quantize_pallas(x: jax.Array, *, block_rows: int = 256,
-                    interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                    interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """x: (R, C) -> (int8 (R, C), fp32 scales (R, 1))."""
     R, C = x.shape
     block_rows = min(block_rows, R)

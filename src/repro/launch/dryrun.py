@@ -1,9 +1,11 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-# ^^ MUST precede every other import: jax locks the device count at first
-# init.  This module is the ONLY place the 512 placeholder devices exist;
-# tests and benchmarks see the real single CPU device.
+# ^^ MUST precede every other import: jax locks the platform and the
+# device count at first init.  The dry-run is a CPU tool: it compiles
+# for 512 placeholder host devices, and its children inherit the pin, so
+# none of them reaches for an accelerator.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -136,7 +138,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
     }
 
     t0 = time.time()
-    with mesh, active_rules(rules, mesh):
+    with jax.set_mesh(mesh), active_rules(rules, mesh):
         if cell.mode == "train":
             opt = M.opt_for(cfg)
             step = make_train_step(cfg, opt, num_microbatches=cfg.microbatches)

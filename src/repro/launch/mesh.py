@@ -17,7 +17,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig, ShapeCell
@@ -30,7 +30,9 @@ from repro.train.optimizer import AdamWConfig, opt_state_specs
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which only refers to Auto axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def arch_rules(cfg: ModelConfig, multi_pod: bool) -> Rules:

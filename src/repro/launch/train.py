@@ -6,10 +6,10 @@
 * ``--tiny`` runs the architecture's reduced config (CPU-friendly); the
   full configs are for real accelerator meshes — their distribution is
   proven by ``repro.launch.dryrun``.
-* ``--mesh single|multi`` binds the production sharding rules when the
-  process has enough devices (on a TPU pod slice); otherwise the step
-  runs unsharded with identical semantics (tested equal in
-  tests/test_multidevice.py).
+* ``--mesh single|multi`` binds the production sharding rules; it needs
+  the mesh's devices (a TPU pod slice) and refuses to start without
+  them.  Sharded and unsharded steps are tested equal in
+  tests/test_multidevice.py.
 * Checkpoints flow through the selected consistency layer with SCR
   partner redundancy; ``--fail-at`` simulates a host failure and elastic
   restart mid-run (the fault-tolerance path is exercised, not mocked).
@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import statistics
 import time
 
 import jax
@@ -28,12 +29,15 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import ARCHS, get_config, tiny_config
 from repro.data.pipeline import synthetic_batch
 from repro.launch import mesh as M
+from repro.launch.cache import use_compile_cache
 from repro.models.sharding import active_rules
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_step import make_train_step, train_state_init
 
 
-def main(argv=None) -> int:
+def main(argv=None) -> dict:
+    """Train; returns the final ``loss``, the median ``step_s`` after the
+    first (compiling) step, and ``compiles`` of the train step."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen3-32b", choices=sorted(ARCHS))
     ap.add_argument("--tiny", action="store_true",
@@ -68,29 +72,35 @@ def main(argv=None) -> int:
     if args.mesh != "none":
         need = 512 if args.mesh == "multi" else 256
         if jax.device_count() < need:
-            print(f"[launch] {need} devices required for --mesh "
-                  f"{args.mesh}, have {jax.device_count()}; "
-                  "running unsharded (same numerics).")
-        else:
-            mesh = M.make_production_mesh(multi_pod=args.mesh == "multi")
-            rules = M.arch_rules(cfg, args.mesh == "multi")
+            ap.error(f"--mesh {args.mesh} needs {need} devices, "
+                     f"have {jax.device_count()}")
+        mesh = M.make_production_mesh(multi_pod=args.mesh == "multi")
+        rules = M.arch_rules(cfg, args.mesh == "multi")
+
+    use_compile_cache()
 
     state = train_state_init(jax.random.PRNGKey(0), cfg, opt)
     mgr = CheckpointManager(model=args.consistency,
                             num_hosts=args.ckpt_hosts, partner=True)
 
+    jitted = jax.jit(step_fn)
+    step_s = []
+    loss = float("nan")
+
     def run_steps(state, start):
-        jitted = jax.jit(step_fn)
-        t0, last = time.time(), start
+        nonlocal loss
+        last = start
         for i in range(start, args.steps):
             batch = synthetic_batch(jax.random.fold_in(
                 jax.random.PRNGKey(7), i), cfg, args.batch, args.seq)
+            t0 = time.perf_counter()
             state, metrics = jitted(state, batch)
+            loss = float(metrics["loss"])        # waits for the step
+            step_s.append(time.perf_counter() - t0)
             last = i + 1
             if last % 5 == 0 or last == args.steps:
-                dt = (time.time() - t0) / max(last - start, 1)
-                print(f"step {last:5d}  loss {float(metrics['loss']):.4f}"
-                      f"  {dt:.2f}s/step")
+                print(f"step {last:5d}  loss {loss:.4f}"
+                      f"  {step_s[-1]:.3f}s/step")
             if args.ckpt_every and last % args.ckpt_every == 0:
                 mgr.save(last, state)
                 print(f"step {last:5d}  checkpoint saved "
@@ -103,7 +113,7 @@ def main(argv=None) -> int:
         start = 0
         while True:
             if mesh is not None:
-                with mesh, active_rules(rules, mesh):
+                with jax.set_mesh(mesh), active_rules(rules, mesh):
                     state, start, failed = run_steps(state, start)
             else:
                 state, start, failed = run_steps(state, start)
@@ -117,16 +127,18 @@ def main(argv=None) -> int:
             print(f"[launch] host failure at step {start}; elastic "
                   f"restart from checkpoint {ck} on "
                   f"{args.ckpt_hosts - 1} hosts (partner copy)")
-            state = mgr.restore(ck, state,
-                                num_hosts_new=args.ckpt_hosts - 1,
-                                failed_hosts=[1])
+            state = jax.device_put(mgr.restore(
+                ck, state, num_hosts_new=args.ckpt_hosts - 1,
+                failed_hosts=[1]))
             start = ck
             args.fail_at = 0
 
     run(state)
-    print("done")
-    return 0
+    out = {"loss": loss, "compiles": jitted._cache_size(),
+           "step_s": statistics.median(step_s[1:]) if step_s[1:] else None}
+    print(f"done: {out}")
+    return out
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    main()

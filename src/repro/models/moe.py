@@ -33,11 +33,6 @@ from repro.models.config import ModelConfig
 from repro.models.layers import _dense
 from repro.models.sharding import current_context
 
-try:  # jax >= 0.4.35 re-export
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
 Params = Dict[str, Any]
 
 
@@ -218,12 +213,9 @@ def _moe_forward_a2a(p: Params, x: jax.Array, cfg: ModelConfig, rules, mesh
         aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
         return y.reshape(Bl, Tl, Dl), aux
 
-    kwargs = dict(mesh=mesh,
-                  in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
-                  out_specs=(x_spec, P()))
-    try:
-        fn = shard_map(local, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover - older jax uses check_rep
-        fn = shard_map(local, check_rep=False, **kwargs)
+    fn = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
+        out_specs=(x_spec, P()), check_vma=False)
     wg = p["wg"] if has_wg else p["wi"]
     return fn(x, p["router"], p["wi"], wg, p["wo"])

@@ -82,7 +82,7 @@ def test_ssm_scan_vs_ref(B, T, I, N, impl):
     C = jax.random.normal(ks[4], (B, T, N))
     D = jax.random.normal(ks[5], (I,))
     if impl == "pallas":
-        y, h = ssm_scan_pallas(x, dt, A, Bm, C, D)
+        y, h = ssm_scan_pallas(x, dt, A, Bm, C, D, interpret=True)
     else:
         y, h = ops.ssm_scan(x, dt, A, Bm, C, D, impl="chunked", time_chunk=4)
     yr, hr = ref.ssm_scan_ref(x, dt, A, Bm, C, D)
@@ -109,7 +109,7 @@ def test_ssm_step_matches_scan():
     np.testing.assert_allclose(h, h_ref, atol=1e-4, rtol=1e-4)
 
 
-RGLRU_CASES = [(1, 8, 4), (2, 16, 8), (1, 13, 6)]  # B, T, L
+RGLRU_CASES = [(1, 8, 4), (2, 16, 8), (1, 13, 6), (1, 20, 6)]  # B, T, L
 
 
 @pytest.mark.parametrize("B,T,L", RGLRU_CASES)
@@ -121,7 +121,7 @@ def test_rglru_vs_ref(B, T, L, impl):
     i = jax.random.normal(ks[2], (B, T, L))
     lam = jax.random.normal(ks[3], (L,))
     if impl == "pallas":
-        hs, hT = rglru_pallas(x, a, i, lam)
+        hs, hT = rglru_pallas(x, a, i, lam, interpret=True)
     else:
         hs, hT = ops.rglru(x, a, i, lam, impl="assoc")
     hr, hTr = ref.rglru_ref(x, a, i, lam)
@@ -162,7 +162,7 @@ def test_rglru_h0_seeding():
 def test_quantize_pallas_vs_ref(shape):
     x = jax.random.normal(jax.random.PRNGKey(8), shape) * 3.0
     qr, sr = ref.quantize_ref(x)
-    qp, sp = quantize_pallas(x)
+    qp, sp = quantize_pallas(x, interpret=True)
     np.testing.assert_allclose(sp, sr, atol=1e-6, rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(qp), np.asarray(qr))
     back = ops.dequantize(qp, sp)

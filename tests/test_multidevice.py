@@ -1,6 +1,7 @@
-"""Multi-device semantics, run in a SUBPROCESS with 8 forced host devices
-(jax pins the device count at first init, so the main pytest process must
-stay at 1 device for every other test).
+"""Multi-device semantics, run in a SUBPROCESS pinned to the CPU with 8
+forced host devices (jax pins the platform and the device count at first
+init, so the main pytest process must stay at 1 device for every other
+test).
 
 Covers: MoE a2a == sort_scatter numerics, shard_tree constraint binding,
 mesh construction, and a tiny end-to-end sharded train step.
@@ -15,10 +16,11 @@ import pytest
 
 SCRIPT = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType
 
     from repro.configs.registry import tiny_config
     from repro.models import moe as M
@@ -31,7 +33,8 @@ SCRIPT = textwrap.dedent("""
     from repro.train.train_step import make_train_step, train_state_init
 
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = rules_for("tp", multi_pod=False)
 
     # ---- 1) a2a MoE == sort_scatter (no-drop capacity) -----------------
@@ -46,7 +49,7 @@ SCRIPT = textwrap.dedent("""
         M.capacity(cfg, x.shape[0] * x.shape[1]))
     y_ref = y_ref.reshape(x.shape)
 
-    with mesh, active_rules(rules, mesh):
+    with jax.set_mesh(mesh), active_rules(rules, mesh):
         y_a2a, aux_a2a = jax.jit(
             lambda p, x: M.moe_forward(p, x, cfg))(p, x)
     np.testing.assert_allclose(np.asarray(y_a2a), np.asarray(y_ref),
@@ -64,7 +67,7 @@ SCRIPT = textwrap.dedent("""
     batch = synthetic_batch(jax.random.PRNGKey(1), cfg2, 8, 16)
     step = make_train_step(cfg2, opt, num_microbatches=2)
     s_plain, m_plain = jax.jit(step)(state, batch)
-    with mesh, active_rules(rules, mesh):
+    with jax.set_mesh(mesh), active_rules(rules, mesh):
         ss = state_shardings(cfg2, mesh, rules)
         bs = batch_shardings(cfg2, cell, mesh, rules)
         s_shard, m_shard = jax.jit(
@@ -85,7 +88,7 @@ SCRIPT = textwrap.dedent("""
     toks = jax.random.randint(jax.random.PRNGKey(2), (8, 12), 0,
                               cfg3.vocab, jnp.int32)
     plain, _ = T.forward(params3, toks, cfg3)
-    with mesh, active_rules(rules, mesh):
+    with jax.set_mesh(mesh), active_rules(rules, mesh):
         shrd, _ = jax.jit(lambda p, t: T.forward(p, t, cfg3))(params3, toks)
     np.testing.assert_allclose(np.asarray(plain), np.asarray(shrd),
                                atol=5e-4, rtol=5e-4)
