@@ -27,8 +27,10 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
+from repro import telemetry
 from repro.core.basefs import BaseFS
 from repro.core.consistency import FileHandle, make_fs
 from repro.checkpoint.serialization import (
@@ -89,61 +91,72 @@ class CheckpointManager:
     def save(self, step: int, tree: Any) -> dict:
         """Write one checkpoint; returns the manifest."""
         H = self.num_hosts
-        arrays = serialize_tree(tree)
-        manifest: dict = {"step": step, "num_hosts": H, "leaves": {}}
-        self.fs.ledger.mark_phase(f"ckpt_save_{step}")
+        nbytes = sum(int(np.size(x)) * np.result_type(x).itemsize
+                     for x in jax.tree.leaves(tree))
+        with telemetry.span("ckpt.save", step=step, bytes=nbytes):
+            with telemetry.span("ckpt.save.serialize"):
+                arrays = serialize_tree(tree)
+            manifest: dict = {"step": step, "num_hosts": H, "leaves": {}}
+            self.fs.ledger.mark_phase(f"ckpt_save_{step}")
 
-        # Host-major write order; the DES reconstructs real concurrency.
-        offsets = {h: 0 for h in range(H)}
-        handles: Dict[int, FileHandle] = {}
-        phandles: Dict[int, FileHandle] = {}
-        for h in range(H):
-            handles[h] = self.layer.open(h, _shard_path(self.base, step, h),
-                                         node=h)
-            self._open_session(handles[h])
-            if self.partner:
-                # Partner copy lands on the partner's NODE (its burst buffer)
-                # but is written by this host's rank group (SCR semantics).
-                p = self.partner_of(h)
-                phandles[h] = self.layer.open(
-                    READER_BASE + 100_000 + h,
-                    _shard_path(self.base, step, h, partner=True), node=p)
-                self._open_session(phandles[h])
-
-        for path, arr in arrays.items():
-            nrows = arr.shape[0] if arr.ndim > 0 else 1
-            flat2d = arr.reshape(nrows, -1)
-            rowbytes = flat2d[0:1].tobytes().__len__() if nrows else 0
-            parts = []
-            for h, (rs, re) in enumerate(row_partition(nrows, H)):
-                if re <= rs:
-                    continue
-                data = flat2d[rs:re].tobytes()
-                self.layer.write(handles[h], data)
+            # Host-major write order; the DES reconstructs real concurrency.
+            offsets = {h: 0 for h in range(H)}
+            handles: Dict[int, FileHandle] = {}
+            phandles: Dict[int, FileHandle] = {}
+            for h in range(H):
+                handles[h] = self.layer.open(
+                    h, _shard_path(self.base, step, h), node=h)
+                self._open_session(handles[h])
                 if self.partner:
-                    self.layer.write(phandles[h], data)
-                parts.append({"host": h, "rows": [rs, re],
-                              "offset": offsets[h], "nbytes": len(data)})
-                offsets[h] += len(data)
-            manifest["leaves"][path] = {
-                "shape": list(arr.shape), "dtype": str(arr.dtype),
-                "rowbytes": rowbytes, "parts": parts,
-            }
+                    # Partner copy lands on the partner's NODE (its burst
+                    # buffer) but is written by this host's rank group (SCR
+                    # semantics).
+                    p = self.partner_of(h)
+                    phandles[h] = self.layer.open(
+                        READER_BASE + 100_000 + h,
+                        _shard_path(self.base, step, h, partner=True),
+                        node=p)
+                    self._open_session(phandles[h])
 
-        for h in range(H):                       # publish shards FIRST
-            self._publish(handles[h])
-            if self.partner:
-                self._publish(phandles[h])
-        # ... THEN the manifest (the hb edge restart relies on).
-        mfh = self.layer.open(0, _manifest_path(self.base, step), node=0)
-        self._open_session(mfh)
-        self.layer.write(mfh, manifest_to_json(manifest))
-        self._publish(mfh)
-        self.manifests[step] = manifest
-        self._handles[step] = {**handles, "manifest": mfh}
-        for h, pfh in phandles.items():
-            self._handles[step][("partner", h)] = pfh
-        return manifest
+            with telemetry.span("ckpt.save.write"):
+                for path, arr in arrays.items():
+                    nrows = arr.shape[0] if arr.ndim > 0 else 1
+                    flat2d = arr.reshape(nrows, -1)
+                    rowbytes = (flat2d[0:1].tobytes().__len__()
+                                if nrows else 0)
+                    parts = []
+                    for h, (rs, re) in enumerate(row_partition(nrows, H)):
+                        if re <= rs:
+                            continue
+                        data = flat2d[rs:re].tobytes()
+                        self.layer.write(handles[h], data)
+                        if self.partner:
+                            self.layer.write(phandles[h], data)
+                        parts.append({"host": h, "rows": [rs, re],
+                                      "offset": offsets[h],
+                                      "nbytes": len(data)})
+                        offsets[h] += len(data)
+                    manifest["leaves"][path] = {
+                        "shape": list(arr.shape), "dtype": str(arr.dtype),
+                        "rowbytes": rowbytes, "parts": parts,
+                    }
+
+            with telemetry.span("ckpt.save.publish"):
+                for h in range(H):                   # publish shards FIRST
+                    self._publish(handles[h])
+                    if self.partner:
+                        self._publish(phandles[h])
+                # ... THEN the manifest (the hb edge restart relies on).
+                mfh = self.layer.open(0, _manifest_path(self.base, step),
+                                      node=0)
+                self._open_session(mfh)
+                self.layer.write(mfh, manifest_to_json(manifest))
+                self._publish(mfh)
+            self.manifests[step] = manifest
+            self._handles[step] = {**handles, "manifest": mfh}
+            for h, pfh in phandles.items():
+                self._handles[step][("partner", h)] = pfh
+            return manifest
 
     # ------------------------------------------------------------------
     def read_manifest(self, step: int, reader: int = READER_BASE) -> dict:
@@ -165,56 +178,66 @@ class CheckpointManager:
         those source shards to be served from the partner copy.
         """
         Hn = num_hosts_new or self.num_hosts
-        self.fs.ledger.mark_phase(f"ckpt_restore_{step}")
-        manifest = self.read_manifest(step)
-        failed = set(failed_hosts)
+        with telemetry.span("ckpt.restore", step=step, hosts=Hn):
+            self.fs.ledger.mark_phase(f"ckpt_restore_{step}")
+            with telemetry.span("ckpt.restore.manifest"):
+                manifest = self.read_manifest(step)
+            failed = set(failed_hosts)
 
-        # One reader client per restart host; each opens each source file
-        # at most once per session (this is where session >> commit).
-        handles: Dict[Tuple[int, int, bool], FileHandle] = {}
+            # One reader client per restart host; each opens each source
+            # file at most once per session (this is where session >>
+            # commit).
+            handles: Dict[Tuple[int, int, bool], FileHandle] = {}
 
-        def get_handle(reader_host: int, src_host: int, partner: bool
-                       ) -> FileHandle:
-            key = (reader_host, src_host, partner)
-            if key not in handles:
-                fh = self.layer.open(
-                    READER_BASE + reader_host,
-                    _shard_path(self.base, step, src_host, partner=partner),
-                    node=src_host if not partner
-                    else self.partner_of(src_host))
-                self._open_session(fh)
-                handles[key] = fh
-            return handles[key]
+            def get_handle(reader_host: int, src_host: int, partner: bool
+                           ) -> FileHandle:
+                key = (reader_host, src_host, partner)
+                if key not in handles:
+                    fh = self.layer.open(
+                        READER_BASE + reader_host,
+                        _shard_path(self.base, step, src_host,
+                                    partner=partner),
+                        node=src_host if not partner
+                        else self.partner_of(src_host))
+                    self._open_session(fh)
+                    handles[key] = fh
+                return handles[key]
 
-        arrays: Dict[str, np.ndarray] = {}
-        for path, meta in manifest["leaves"].items():
-            shape, dtype = tuple(meta["shape"]), np.dtype(meta["dtype"])
-            nrows = shape[0] if shape else 1
-            buf = np.empty((nrows, meta["rowbytes"]), np.uint8)
-            new_parts = row_partition(nrows, Hn)
-            for rh, (nrs, nre) in enumerate(new_parts):
-                for part in meta["parts"]:
-                    rs, re = part["rows"]
-                    lo, hi = max(rs, nrs), min(re, nre)
-                    if hi <= lo:
-                        continue
-                    src = part["host"]
-                    use_partner = src in failed
-                    if use_partner and not self.partner:
-                        raise RuntimeError(
-                            f"host {src} failed and no partner copy exists")
-                    fh = get_handle(rh, src, use_partner)
-                    off = part["offset"] + (lo - rs) * meta["rowbytes"]
-                    self.layer.seek(fh, off)
-                    data = self.layer.read(fh, (hi - lo) * meta["rowbytes"])
-                    # Checkpoint state round-trips REAL bytes: materialize
-                    # the lazy payload at the consumer.
-                    buf[lo:hi] = np.frombuffer(
-                        bytes(data), np.uint8).reshape(hi - lo,
-                                                       meta["rowbytes"])
-            arr = buf.tobytes()
-            arrays[path] = np.frombuffer(arr, dtype).reshape(shape).copy()
-        return deserialize_tree(template, arrays)
+            arrays: Dict[str, np.ndarray] = {}
+            with telemetry.span("ckpt.restore.read"):
+                for path, meta in manifest["leaves"].items():
+                    shape, dtype = tuple(meta["shape"]), np.dtype(meta["dtype"])
+                    nrows = shape[0] if shape else 1
+                    buf = np.empty((nrows, meta["rowbytes"]), np.uint8)
+                    new_parts = row_partition(nrows, Hn)
+                    for rh, (nrs, nre) in enumerate(new_parts):
+                        for part in meta["parts"]:
+                            rs, re = part["rows"]
+                            lo, hi = max(rs, nrs), min(re, nre)
+                            if hi <= lo:
+                                continue
+                            src = part["host"]
+                            use_partner = src in failed
+                            if use_partner and not self.partner:
+                                raise RuntimeError(
+                                    f"host {src} failed and no partner copy "
+                                    "exists")
+                            fh = get_handle(rh, src, use_partner)
+                            off = (part["offset"]
+                                   + (lo - rs) * meta["rowbytes"])
+                            self.layer.seek(fh, off)
+                            data = self.layer.read(
+                                fh, (hi - lo) * meta["rowbytes"])
+                            # Checkpoint state round-trips REAL bytes:
+                            # materialize the lazy payload at the consumer.
+                            buf[lo:hi] = np.frombuffer(
+                                bytes(data), np.uint8).reshape(
+                                    hi - lo, meta["rowbytes"])
+                    arr = buf.tobytes()
+                    arrays[path] = np.frombuffer(arr, dtype).reshape(
+                        shape).copy()
+            with telemetry.span("ckpt.restore.assemble"):
+                return deserialize_tree(template, arrays)
 
     # ------------------------------------------------------------------
     def flush(self, step: int) -> None:

@@ -15,6 +15,8 @@ from typing import Any, Dict, List, Tuple
 import jax
 import numpy as np
 
+from repro import telemetry
+
 SEP = "/"
 
 
@@ -47,6 +49,8 @@ def deserialize_tree(template, arrays: Dict[str, np.ndarray]):
         key = SEP.join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
         arr = arrays[key]
+        if isinstance(leaf, jax.Array):
+            telemetry.count("ckpt.restore.d2h_bytes", leaf.nbytes)
         leaves.append(arr.reshape(np.shape(leaf)).astype(
             np.asarray(leaf).dtype))
     return jax.tree_util.tree_unflatten(flat[1], leaves)

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
+from repro.core.basefs import EventKind
 from repro.data.dlio import PreloadedStore
 from repro.models.config import ModelConfig
 
@@ -61,10 +63,18 @@ class TokenPipeline:
                 ) -> Iterator[Dict[str, jax.Array]]:
         assign = self.store.epoch_assignment(epoch, self.seed)
         flat = [i for sub in assign for i in sub]
+        ledger = self.store.fs.ledger
         for b0 in range(0, len(flat) - self.B + 1, self.B):
-            toks = []
-            for idx in flat[b0 : b0 + self.B]:
-                raw = self.store.read_sample(idx, reader_host=reader_host)
-                toks.append(np.frombuffer(raw, np.int32)[: self.seq])
-            tokens = jnp.asarray(np.stack(toks))
-            yield {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}
+            with telemetry.span("ingest.batch"):
+                q0 = ledger.count(EventKind.RPC, "query")
+                toks = []
+                for idx in flat[b0 : b0 + self.B]:
+                    raw = self.store.read_sample(idx, reader_host=reader_host)
+                    toks.append(np.frombuffer(raw, np.int32)[: self.seq])
+                tokens = jnp.asarray(np.stack(toks))
+                batch = {"tokens": tokens,
+                         "labels": jnp.roll(tokens, -1, axis=1)}
+                telemetry.count("ingest.samples", len(toks))
+                telemetry.count("ingest.queries",
+                                ledger.count(EventKind.RPC, "query") - q0)
+            yield batch

@@ -156,7 +156,8 @@ def attn_forward(p: Params, x: jax.Array, cfg: ModelConfig, *,
     if positions is None and kv_from is None:
         positions = jnp.arange(x.shape[1])
     q, k, v = attn_qkv(p, x, cfg, positions, kv_from)
-    o = ops.flash_attention(q, k, v, causal=causal, window=window)
+    with jax.named_scope("attention"):
+        o = ops.flash_attention(q, k, v, causal=causal, window=window)
     return attn_out(p, o)
 
 

@@ -38,18 +38,19 @@ def train_state_init(key, cfg: ModelConfig, opt: AdamWConfig) -> TrainState:
 def loss_fn(params, batch: Dict[str, jax.Array], cfg: ModelConfig,
             aux_weight: float = 0.01) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Causal-LM cross entropy.  batch: tokens, labels (+frames/patches)."""
-    extras = {k: batch[k] for k in ("frames", "patches") if k in batch}
-    logits, aux = T.forward(params, batch["tokens"], cfg, **extras)
-    labels = batch["labels"]
-    Tl = labels.shape[1]
-    logits = logits[:, -Tl:].astype(jnp.float32)     # vision prefix cut off
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    ntok = jnp.maximum(mask.sum(), 1.0)
-    ce = jnp.sum((logz - gold) * mask) / ntok
-    loss = ce + aux_weight * aux
-    return loss, {"ce": ce, "moe_aux": aux}
+    with jax.named_scope("forward"):
+        extras = {k: batch[k] for k in ("frames", "patches") if k in batch}
+        logits, aux = T.forward(params, batch["tokens"], cfg, **extras)
+        labels = batch["labels"]
+        Tl = labels.shape[1]
+        logits = logits[:, -Tl:].astype(jnp.float32)  # vision prefix cut off
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        mask = (labels >= 0).astype(jnp.float32)
+        ntok = jnp.maximum(mask.sum(), 1.0)
+        ce = jnp.sum((logz - gold) * mask) / ntok
+        loss = ce + aux_weight * aux
+        return loss, {"ce": ce, "moe_aux": aux}
 
 
 def make_train_step(cfg: ModelConfig, opt: AdamWConfig,
@@ -97,7 +98,8 @@ def make_train_step(cfg: ModelConfig, opt: AdamWConfig,
                 (gzero, jnp.zeros(()), jnp.zeros(())), mbatch)
             aux = {"ce": ce, "moe_aux": jnp.zeros(())}
 
-        newp, newopt, om = adamw_update(grads, state["opt"], params, opt)
+        with jax.named_scope("optimizer"):
+            newp, newopt, om = adamw_update(grads, state["opt"], params, opt)
         metrics = {"loss": loss, **aux, **om, "step": state["step"] + 1}
         return (
             {"params": newp, "opt": newopt, "step": state["step"] + 1},

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import tiny_config
 from repro.core.basefs import EventKind
@@ -99,3 +100,22 @@ def test_manifest_orders_after_shards():
     # manifest writer is client 0 and the LAST attach must be the manifest's
     assert attaches, "no attach RPCs recorded"
     assert attaches[-1].client == 0
+
+
+def test_save_and_restore_spans_and_host_copies():
+    state = _state()
+    mgr = CheckpointManager(model="commit", num_hosts=4)
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(state))
+    with telemetry.recording() as rec:
+        mgr.save(5, state)
+        out = mgr.restore(5, state, num_hosts_new=3, failed_hosts=[1])
+    _assert_tree_equal(state, out)
+    assert [(s.name, s.parent) for s in rec.spans] == [
+        ("ckpt.save", None), ("ckpt.save.serialize", 0),
+        ("ckpt.save.write", 0), ("ckpt.save.publish", 0),
+        ("ckpt.restore", None), ("ckpt.restore.manifest", 4),
+        ("ckpt.restore.read", 4), ("ckpt.restore.assemble", 4)]
+    assert rec.spans[0].attrs == {"step": 5, "bytes": nbytes}
+    assert rec.spans[4].attrs == {"step": 5, "hosts": 3}
+    # The template's dtypes are read from the device: one full copy.
+    assert rec.counters == {"ckpt.restore.d2h_bytes": nbytes}

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.configs.registry import tiny_config
 from repro.core.basefs import EventKind
 from repro.data.dlio import PreloadedStore
@@ -68,6 +69,28 @@ def test_token_pipeline_feeds_training_shapes():
         # next-token alignment
         np.testing.assert_array_equal(np.asarray(b["tokens"][:, 1:]),
                                       np.asarray(b["labels"][:, :-1]))
+
+
+@pytest.mark.parametrize("model", ["commit", "session"])
+def test_token_pipeline_counts_samples_and_queries(model):
+    """Every fed sample costs one query under either model today:
+    ``read_sample`` opens a handle per sample, and under session that
+    open is a ``session_open``."""
+    cfg = dataclasses.replace(tiny_config("starcoder2-3b"),
+                              dtype=jnp.float32)
+    samples = [np.full((9,), i, np.int32) for i in range(16)]
+    store = PreloadedStore(model, num_hosts=2, samples_per_host=8,
+                           procs_per_host=1, samples=samples)
+    store.preload()
+    pipe = TokenPipeline(store, cfg, batch_size=4, seq=8)
+    q0 = store.fs.ledger.count(EventKind.RPC, "query")
+    with telemetry.recording() as rec:
+        batches = list(pipe.batches(epoch=0))
+    assert rec.counters == {
+        "ingest.samples": 16,
+        "ingest.queries": store.fs.ledger.count(EventKind.RPC, "query") - q0}
+    assert rec.counters["ingest.queries"] / rec.counters["ingest.samples"] == 1
+    assert [s.name for s in rec.spans] == ["ingest.batch"] * len(batches)
 
 
 @pytest.mark.parametrize("model", ["commit", "session"])
