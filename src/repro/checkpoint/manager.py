@@ -206,9 +206,13 @@ class CheckpointManager:
             arrays: Dict[str, np.ndarray] = {}
             with telemetry.span("ckpt.restore.read"):
                 for path, meta in manifest["leaves"].items():
-                    shape, dtype = tuple(meta["shape"]), np.dtype(meta["dtype"])
+                    shape = tuple(meta["shape"])
                     nrows = shape[0] if shape else 1
-                    buf = np.empty((nrows, meta["rowbytes"]), np.uint8)
+                    rowbytes = meta["rowbytes"]
+                    # The leaf itself is the read buffer: each part's
+                    # bytes land in its rows, the one host copy.
+                    arr = np.empty(shape, np.dtype(meta["dtype"]))
+                    dst = memoryview(arr.reshape(-1).view(np.uint8))
                     new_parts = row_partition(nrows, Hn)
                     for rh, (nrs, nre) in enumerate(new_parts):
                         for part in meta["parts"]:
@@ -223,19 +227,18 @@ class CheckpointManager:
                                     f"host {src} failed and no partner copy "
                                     "exists")
                             fh = get_handle(rh, src, use_partner)
-                            off = (part["offset"]
-                                   + (lo - rs) * meta["rowbytes"])
+                            off = part["offset"] + (lo - rs) * rowbytes
                             self.layer.seek(fh, off)
-                            data = self.layer.read(
-                                fh, (hi - lo) * meta["rowbytes"])
+                            data = self.layer.read(fh, (hi - lo) * rowbytes)
                             # Checkpoint state round-trips REAL bytes:
                             # materialize the lazy payload at the consumer.
-                            buf[lo:hi] = np.frombuffer(
-                                bytes(data), np.uint8).reshape(
-                                    hi - lo, meta["rowbytes"])
-                    arr = buf.tobytes()
-                    arrays[path] = np.frombuffer(arr, dtype).reshape(
-                        shape).copy()
+                            pos = lo * rowbytes
+                            for chunk in data.chunks():
+                                dst[pos:pos + len(chunk)] = chunk
+                                pos += len(chunk)
+                            telemetry.count("ckpt.restore.host_copy_bytes",
+                                            pos - lo * rowbytes)
+                    arrays[path] = arr
             with telemetry.span("ckpt.restore.assemble"):
                 return deserialize_tree(template, arrays)
 

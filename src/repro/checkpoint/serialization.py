@@ -15,8 +15,6 @@ from typing import Any, Dict, List, Tuple
 import jax
 import numpy as np
 
-from repro import telemetry
-
 SEP = "/"
 
 
@@ -42,17 +40,21 @@ def tree_manifest(tree) -> Dict[str, Dict[str, Any]]:
 
 
 def deserialize_tree(template, arrays: Dict[str, np.ndarray]):
-    """Rebuild a pytree shaped like ``template`` from named arrays."""
+    """Rebuild a pytree shaped like ``template`` from named arrays.
+
+    Each leaf's dtype and shape come from the template's metadata, so a
+    template on the device is never copied to the host; an array that
+    already has them is handed back as it is.
+    """
     flat = jax.tree_util.tree_flatten_with_path(template)
     leaves = []
     for path, leaf in flat[0]:
         key = SEP.join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
-        arr = arrays[key]
-        if isinstance(leaf, jax.Array):
-            telemetry.count("ckpt.restore.d2h_bytes", leaf.nbytes)
-        leaves.append(arr.reshape(np.shape(leaf)).astype(
-            np.asarray(leaf).dtype))
+        if not hasattr(leaf, "dtype"):     # a Python scalar
+            leaf = np.asarray(leaf)
+        leaves.append(arrays[key].reshape(leaf.shape).astype(
+            leaf.dtype, copy=False))
     return jax.tree_util.tree_unflatten(flat[1], leaves)
 
 
