@@ -1,10 +1,11 @@
 """Reduction of a profiler trace to the benchmark's device numbers.
 
 A trace is read into plain lists of ``(name, start_ns, duration_ns)``:
-the device's operations and its XLA module executions, and the host
-spans that the harness writes with ``jax.profiler.TraceAnnotation``.
-Everything after ``load`` is arithmetic on those lists, so the tests can
-build a trace by hand.
+the device's operations and its XLA module executions, the host spans
+that the harness writes with ``jax.profiler.TraceAnnotation``, and the
+program's own spans (``repro.telemetry``) on the same clock.  Everything
+after ``load`` is arithmetic on those lists, so the tests can build a
+trace by hand.
 """
 
 from __future__ import annotations
@@ -13,11 +14,13 @@ import glob
 import os
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]          # name, start ns, duration ns
+Interval = Tuple[str, float, float]       # name, start ns, end ns
 
 HOST_SPANS = ("window", "ingest", "step", "ckpt_save", "restore")
+PROGRAM_PREFIXES = ("ckpt.", "ingest.")
 
 
 class NoDeviceTrace(RuntimeError):
@@ -29,6 +32,7 @@ class Trace:
     ops: List[List[Event]]                # per device plane
     modules: List[List[Event]]            # per device plane
     spans: Dict[str, List[Tuple[float, float]]] = field(default_factory=dict)
+    program: List[Interval] = field(default_factory=list)
 
     @property
     def window(self) -> Tuple[float, float]:
@@ -45,6 +49,7 @@ def load(log_dir: str) -> Trace:
     data = ProfileData.from_file(path)
     ops, modules = [], []
     spans: Dict[str, List[Tuple[float, float]]] = defaultdict(list)
+    program: List[Interval] = []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {line.name: line for line in plane.lines}
@@ -62,11 +67,14 @@ def load(log_dir: str) -> Trace:
                     if e.name in HOST_SPANS:
                         spans[e.name].append(
                             (e.start_ns, e.start_ns + e.duration_ns))
+                    elif e.name.startswith(PROGRAM_PREFIXES):
+                        program.append(
+                            (e.name, e.start_ns, e.start_ns + e.duration_ns))
     if not ops:
         raise NoDeviceTrace(
             "no TPU plane in the trace: planes are "
             f"{[p.name for p in data.planes]}")
-    return Trace(ops, modules, dict(spans))
+    return Trace(ops, modules, dict(spans), program)
 
 
 def _short(name: str) -> str:
@@ -132,18 +140,26 @@ def idle_gaps(events: Sequence[Event], lo: float, hi: float
 
 
 def name_gap(gap: Tuple[float, float],
-             spans: Dict[str, List[Tuple[float, float]]]) -> str:
+             spans: Dict[str, List[Tuple[float, float]]],
+             program: Iterable[Interval] = ()) -> str:
     """The host span (other than the window) that covers most of a gap,
-    or ``"other"`` where none covers any of it."""
-    best, best_cover = "other", 0.0
+    or ``"other"`` where none covers any of it; followed by ``/`` and
+    the innermost program span that covers more than half of it, where
+    one does."""
+    base, best_cover = "other", 0.0
     a, b = gap
     for name, ivs in spans.items():
         if name == "window":
             continue
         cover = sum(max(0.0, min(b, e) - max(a, s)) for s, e in ivs)
         if cover > best_cover:
-            best, best_cover = name, cover
-    return best
+            base, best_cover = name, cover
+    inner: Optional[Tuple[float, str]] = None
+    for name, s, e in program:
+        if 2 * (min(b, e) - max(a, s)) > b - a:
+            if inner is None or e - s < inner[0]:
+                inner = (e - s, name)
+    return base if inner is None else f"{base}/{inner[1]}"
 
 
 def module_time(modules: Sequence[Event], key: str, lo: float, hi: float
@@ -176,7 +192,7 @@ def summarize(trace: Trace, step_key: str = "train_step",
             per_op[name] += d
         t, n = module_time(modules, step_key, lo, hi)
         step_ns, step_n = step_ns + t, step_n + n
-        gaps += [(name_gap(g, trace.spans), g[1] - g[0])
+        gaps += [(name_gap(g, trace.spans, trace.program), g[1] - g[0])
                  for g in idle_gaps(inside, lo, hi)]
     ops_top = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
     gaps_top = sorted(gaps, key=lambda kv: -kv[1])[:top]

@@ -45,6 +45,9 @@ class Window:
     gc_pauses: List[tuple] = field(default_factory=list)
     spans: Dict[str, List[float]] = field(default_factory=lambda:
                                           defaultdict(list))
+    # The program's spans and counters (``repro.telemetry.Recorder``),
+    # kept in traced windows only.
+    telemetry: Optional[object] = None
 
 
 class Job:
@@ -142,8 +145,8 @@ class Job:
         p0 = self.state["params"]
         losses, grads, update1 = [], [], []
         for i in range(n):
-            self.state, metrics = self.step_fn(self.state,
-                                               self.next_batch())
+            batch = self.next_batch()
+            self.state, metrics = self.step_fn(self.state, batch)
             losses.append(float(metrics["loss"]))
             if i == 0:
                 scale = (1 - self.opt["b1"]) * float(metrics["clip"])
@@ -153,6 +156,7 @@ class Job:
                            for x in moved(self.state["params"], p0)]
         update = [float(x) for x in moved(self.state["params"], p0)]
         self.metrics = metrics
+        self.last_batch = batch
         if self.traffic.get("fail_after_save"):
             self._same = jax.jit(_bitwise_same)
             self._same(self.state, self.state).block_until_ready()
@@ -163,6 +167,9 @@ class Job:
     # ---------------------------------------------------------- window
     def window(self, seconds: float, trace_dir: Optional[str] = None
                ) -> Window:
+        """Train for ``seconds`` as the traffic says; with ``trace_dir``,
+        under the profiler and with the program's spans and counters
+        recorded into ``Window.telemetry``."""
         from repro.core.basefs import EventKind
 
         w = Window()
@@ -197,10 +204,8 @@ class Job:
         state, metrics = self.state, self.metrics
         inflight: deque = deque()
         last_save = None
-        if trace_dir is not None:
-            jax.profiler.start_trace(trace_dir)
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("window"):
+        with _traced(trace_dir, w), jax.profiler.TraceAnnotation("window"):
+            t0 = time.perf_counter()
             while True:
                 now = time.perf_counter() - t0
                 if now >= seconds:
@@ -246,14 +251,19 @@ class Job:
                 self.manifest_ok = (self.mgr.read_manifest(last_save[0])
                                     == last_save[1])
             w.seconds = time.perf_counter() - t0
-        if trace_dir is not None:
-            jax.profiler.stop_trace()
         jax.monitoring.unregister_event_duration_listener(on_event)
         gc.callbacks.remove(on_gc)
         w.compiles = len(compiles)
         w.samples = w.steps * self.B
         self.state, self.metrics = state, metrics
         return w
+
+    def step_hlo(self) -> str:
+        """The optimized HLO text of the compiled train step that the
+        window ran: lowered with arrays like the window's own, it is
+        found in the step's cache and compiles nothing."""
+        return self.step_fn.lower(self.state,
+                                  self.last_batch).compile().as_text()
 
     # -------------------------------------------------------- checking
     def ingest_check(self) -> tuple:
@@ -321,9 +331,27 @@ class Job:
 
     def free(self) -> None:
         """Drop every device array the job holds."""
-        self.state = self.metrics = None
+        self.state = self.metrics = self.last_batch = None
         self.fed, self.restore_same = [], []
         gc.collect()
+
+
+@contextlib.contextmanager
+def _traced(trace_dir: Optional[str], w: Window):
+    """The profiler and the program's recording around a window, where
+    ``trace_dir`` asks for them; nothing otherwise."""
+    if trace_dir is None:
+        yield
+        return
+    from repro import telemetry
+
+    with telemetry.recording() as rec:
+        jax.profiler.start_trace(trace_dir)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+    w.telemetry = rec
 
 
 def _bitwise_same(a, b) -> jax.Array:

@@ -5,15 +5,17 @@
 The cell, its configuration file and its traffic file are found by name
 through ``BENCHMARK.json`` at the root of the checkout; a per-layer
 metric is read by ``bench/metrics/<name>.py`` and a configuration's
-plain reference is ``bench/reference/<reference>.py``.  Set-up makes the
-weights and the data from ``--seed``, preloads the store and runs the
-first three training steps (which compile); the window then trains for
-``--seconds``; after it closes, the run checks what the window produced
-(the first steps against the reference, every ingested row, every
-restore, the DES pricing) and prints each compared number beside its
-limit, on standard error and under ``checks`` in the last line of
-standard output.  ``--trace 1`` records the window with the profiler
-and reports the per-layer metrics instead of the end-to-end ones.
+plain reference is ``bench/reference/<reference>.py``, which may bring
+the configuration's own FLOP count (``train_flops_per_step``).  Set-up
+makes the weights and the data from ``--seed``, preloads the store and
+runs the first three training steps (which compile); the window then
+trains for ``--seconds``; after it closes, the run checks what the
+window produced (the first steps against the reference, every ingested
+row, every restore, the DES pricing) and prints each compared number
+beside its limit, on standard error and under ``checks`` in the last
+line of standard output.  ``--trace 1`` records the window with the
+profiler and the program's own spans and counters, and reports the
+per-layer metrics instead of the end-to-end ones.
 
 A run that finds no TPU, or fewer chips than the cell asks for, exits
 non-zero and prints no result.
@@ -66,6 +68,16 @@ def read_per_layer(root: Path, manifest: dict, workload: str, ctx) -> dict:
             if v is not None:
                 values[m["name"]] = v
     return values
+
+
+def step_flops(ref, model: dict, batch: int, seq: int) -> float:
+    """Model FLOPs of one training step: the reference module's own
+    ``train_flops_per_step`` where it defines one, else
+    ``bench/flops.py``'s."""
+    from bench import flops
+
+    count = getattr(ref, "train_flops_per_step", flops.train_flops_per_step)
+    return count(model, batch, seq)
 
 
 def start_jax(root: Path):
@@ -168,7 +180,7 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
     if require_chip:
         check_devices(jax, cell["chips"])
     from bench import devtrace as tr
-    from bench.flops import train_flops_per_step
+    from bench import layers
     from bench.job import Job
 
     ref = load_module(root / "bench" / "reference"
@@ -187,10 +199,18 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
         devs = jax.devices()[:cell["chips"]]
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
                  for d in devs]
-        summary = tr.summarize(tr.load(trace_dir)) if args.trace else None
+        trace = tr.load(trace_dir) if args.trace else None
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
+    t_hlo = time.perf_counter()
+    if trace is not None:
+        summary = tr.summarize(trace)
+        scopes = layers.step_scopes(
+            trace, layers.hlo_scopes(job.step_hlo()).get)
+        trace = None
+    else:
+        summary = scopes = None
 
     t_check = time.perf_counter()
     ingest_bad, first = job.ingest_check()
@@ -215,11 +235,12 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
         wanted = manifest["end_to_end"]
     else:
         ctx = SimpleNamespace(
-            window=w, trace=summary, batch=job.B,
-            flops_per_step=train_flops_per_step(
-                config["model"], job.B, job.seq),
+            workload=cell["name"], model=config["model"], job=config["job"],
+            ref=ref, window=w, trace=summary, batch=job.B,
+            flops_per_step=step_flops(ref, config["model"], job.B, job.seq),
             peak=json.loads((root / "bench" / "peaks.json").read_text())
-            ["devices"].get(jax.devices()[0].device_kind))
+            ["devices"].get(jax.devices()[0].device_kind),
+            telemetry=w.telemetry, scopes=scopes)
         values = read_per_layer(root, manifest, cell["name"], ctx)
         wanted = manifest["per_layer"]
     metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
@@ -244,7 +265,8 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
     print(f"bench: {cell['name']} seed={args.seed} setup_s={setup_s!r} "
           f"window_s={w.seconds!r} steps={w.steps} saves={w.save_s!r} "
           f"restores={w.resume_s!r} compiles_in_window={w.compiles} "
-          f"losses={w.losses!r} checks_s={t_ref - t_check!r} "
+          f"losses={w.losses!r} trace_s={t_check - t_hlo!r} "
+          f"checks_s={t_ref - t_check!r} "
           f"reference_s={t_done - t_ref!r}", file=err)
     print(f"bench: set-up {phases!r}; window {host_stalls(w)}; "
           f"{len(gc.get_objects())} objects tracked", file=err)

@@ -80,3 +80,14 @@ def test_loop_events_are_left_out_of_the_top_operations():
                               ("copy.4", 12, 2)]
     assert tr._short("%fusion.12 = f32[8]{0} fusion(f32[8]{0} %p)") == (
         "fusion.12")
+
+
+def test_breakdown_names_gaps_by_the_program_s_spans():
+    t = _trace()
+    t.program = [("ckpt.save", 69 * MS, 94 * MS),
+                 ("ckpt.save.write", 72 * MS, 90 * MS),
+                 ("ingest.batch", 30 * MS, 31 * MS)]
+    s = tr.summarize(t)
+    assert s.breakdown["idle_gaps"] == [
+        ["ingest", pytest.approx(0.02)],       # no program span has half
+        ["ckpt_save/ckpt.save.write", pytest.approx(0.02)]]

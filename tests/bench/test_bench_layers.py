@@ -47,10 +47,23 @@ def _trace():
 
 def test_step_split_by_scope():
     trace, scopes = _trace()
-    split = L.step_split(trace, scopes.get)
+    got = L.step_scopes(trace, scopes.get)
+    split = {k: got[k] for k in ("module", *L.PARTS, "unscoped", "attention")}
     assert split == {"module": 40.0, "forward": 12.0, "backward": 20.0,
                      "optimizer": 5.0, "unscoped": 3.0, "attention": 14.0}
     assert sum(split[k] for k in L.PARTS) + split["unscoped"] == 40.0
+
+
+def test_step_scopes_by_every_scope_word():
+    trace, scopes = _trace()
+    got = L.step_scopes(trace, scopes.get)
+    assert got["attention"] == 14.0               # forward and backward
+    assert got["_flash_chunked"] == 6.0
+    assert got["train_step"] == 37.0              # every scoped op
+    assert "add" not in got and "exp" not in got  # primitives
+    assert got["forward"] == 12.0                 # the part, not the scope
+    assert L.step_scopes(tr.Trace([[]], [[]], {"window": [(0, 1)]}),
+                         scopes.get) == {}
 
 
 def test_step_part_reads_the_scopes_not_the_primitive():
@@ -60,8 +73,17 @@ def test_step_part_reads_the_scopes_not_the_primitive():
     assert L.step_part(OPT) == "optimizer"
     assert L.step_part("jit(train_step)/add") is None
     assert L.step_part(None) is None
-    assert L.in_attention(FWD_ATTN) and L.in_attention(BWD_ATTN)
-    assert not L.in_attention("jit(train_step)/jvp(forward)/attention")
+    attention = L.step_scopes(*_one_op_trace(FWD_ATTN)).get("attention")
+    assert attention == L.step_scopes(*_one_op_trace(BWD_ATTN))["attention"]
+    assert "attention" not in L.step_scopes(
+        *_one_op_trace("jit(train_step)/jvp(forward)/attention"))
+
+
+def _one_op_trace(op_name):
+    """One 1 ms step of one op whose name stack is ``op_name``."""
+    trace = tr.Trace([[("fusion.1", 0, MS)]], [[("jit_train_step(1)", 0, MS)]],
+                     {"window": [(0, MS)]})
+    return trace, {"fusion.1": op_name}.get
 
 
 HLO = """HloModule jit_step
@@ -145,7 +167,7 @@ def _gap_spans():
 ])
 def test_gaps_take_the_innermost_program_span(gap, name):
     spans, program = _gap_spans()
-    assert L.name_gap(gap, spans, program) == name
+    assert tr.name_gap(gap, spans, program) == name
 
 
 def test_gap_names_stay_as_today_without_program_spans():
@@ -153,11 +175,13 @@ def test_gap_names_stay_as_today_without_program_spans():
     ops = [("fusion.1", 0, 10 * MS), ("fusion.2", 60 * MS, 2 * MS),
            ("fusion.3", 99 * MS, 1 * MS)]
     trace = tr.Trace([ops], [[]], spans)
-    today = [(tr.name_gap(g, spans), (g[1] - g[0]) / 1e9)
+    today = [[tr.name_gap(g, spans), (g[1] - g[0]) / 1e9]
              for g in tr.idle_gaps(ops, 0, 100 * MS)]
-    assert sorted(L.name_gaps(trace, [])) == sorted(today)
-    assert L.name_gaps(trace, program)[0] == (
-        "ckpt_save/ckpt.save.serialize", pytest.approx(0.05))
+    gaps = tr.summarize(trace).breakdown["idle_gaps"]
+    assert sorted(gaps) == sorted(today)
+    trace.program = program
+    assert tr.summarize(trace).breakdown["idle_gaps"][0] == [
+        "ckpt_save/ckpt.save.serialize", pytest.approx(0.05)]
 
 
 def test_host_numbers_from_a_recorder():
@@ -169,13 +193,13 @@ def test_host_numbers_from_a_recorder():
                 with telemetry.span("ckpt.save.serialize"):
                     pass
         with telemetry.span("ckpt.restore"):
-            telemetry.count("ckpt.restore.d2h_bytes", 3_000_000_000)
+            telemetry.count("ckpt.restore.host_copy_bytes", 3_000_000_000)
         telemetry.count("ingest.samples", 8)
         telemetry.count("ingest.queries", 4)
     got = L.host_numbers(rec.spans, rec.counters)
     assert set(got) == {"save_s", "save_serialize_s", "restore_s",
-                        "restore_d2h_gb", "ingest_queries_per_sample"}
-    assert got["restore_d2h_gb"] == 3.0
+                        "restore_host_copy_gb", "ingest_queries_per_sample"}
+    assert got["restore_host_copy_gb"] == 3.0
     assert got["ingest_queries_per_sample"] == 0.5
     assert L.host_numbers([], {}) == {}
 
@@ -205,6 +229,40 @@ def test_a_tiny_ckpt_restart_window_gives_every_checkpoint_number():
               "restore_manifest_s", "restore_read_s", "restore_assemble_s"):
         assert got[k] > 0, k
     assert got["save_s"] <= statistics.fmean(w.save_s)
-    assert got["restore_d2h_gb"] == state_bytes / 1e9
+    assert got["restore_host_copy_gb"] == state_bytes / 1e9
     assert got["ingest_queries_per_sample"] == 1.0
     assert job.restore_check() == 0
+
+
+def test_the_step_s_hlo_text_compiles_nothing():
+    """A traced run takes the HLO text of the step the window ran, from
+    the step's own cache: no compilation after the window."""
+    from bench.job import Job
+    from bench.run import load_module
+
+    config = json.loads(
+        (ROOT / "bench" / "configs" / "gpt2-small-commit.json").read_text())
+    config["model"].update({k: v for k, v in TINY_MODEL.items()
+                            if k in config["model"]})
+    config["job"].update(TINY_JOB)
+    config["storage"]["samples_per_host"] = 8
+    traffic = json.loads(
+        (ROOT / "bench" / "traffic" / "train.json").read_text())
+    ref = load_module(ROOT / "bench" / "reference" / "gpt2.py")
+    job = Job(config, traffic, ref, 2**31 + 13)
+    job.first_steps()
+    job.window(0.5)
+    compiles = []
+
+    def on_event(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        text = job.step_hlo()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+    parts = {L.step_part(v) for v in L.hlo_scopes(text).values()}
+    assert {"forward", "backward", "optimizer"} <= parts
