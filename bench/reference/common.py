@@ -10,6 +10,7 @@ the lower-precision control that ``correct`` has to reject.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
@@ -153,9 +154,26 @@ def ce_sum(mm, head: jax.Array, x: jax.Array, labels: jax.Array,
 
 
 # ------------------------------------------------------------- training
+# The reference's training state on the device, in bytes a parameter:
+# float32 params, AdamW's m and v, and the gradient accumulator.
+STATE_BYTES_PER_PARAM = 16
+
+
+def param_count(layout) -> int:
+    return sum(math.prod(leaf.shape) for leaf in jax.tree.leaves(
+        layout, is_leaf=lambda x: isinstance(x, Leaf)))
+
+
 def leaf_norms(tree) -> List[float]:
     return [float(jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))))
             for x in jax.tree.leaves(tree)]
+
+
+def change_norms(params, p0: List[np.ndarray]) -> List[float]:
+    """Per leaf |params - p0|, with ``p0`` the start weights on the host:
+    one leaf of it on the device at a time."""
+    return [leaf_norms(p - jnp.asarray(q))[0]
+            for p, q in zip(jax.tree.leaves(params), p0)]
 
 
 class Readings(NamedTuple):
@@ -166,25 +184,31 @@ class Readings(NamedTuple):
     update_norms: List[float]     # per leaf: |params after 3 - params at 0|
 
 
-def train3(ref, model: dict, opt: dict, layout, key, rows: List[dict],
-           lowp: Optional[str] = None, block_rows: int = 1) -> Readings:
-    """Three AdamW steps of the reference ``ref`` from the weights that
-    ``init(key, layout)`` makes, over ``rows[k]`` (the batch of step k+1:
-    a dict of host arrays, one row per sequence).  ``lowp`` runs the
-    control: operands and stored parameters rounded to that dtype."""
-    mm = make_mm(lowp)
-    stored = jax.tree.map(lambda leaf: leaf.dtype, layout,
-                          is_leaf=lambda x: isinstance(x, Leaf))
-    if lowp is not None:
-        stored = jax.tree.map(lambda d: LOWER[d], stored)
+def store(p: jax.Array, dtype: str) -> jax.Array:
+    return round_to(p.astype(jnp.float32), dtype)
 
-    def store(p, dtype):
-        return round_to(p.astype(jnp.float32), dtype)
 
-    grad_block = jax.jit(jax.value_and_grad(
-        lambda p, blk: ref.loss_sum(mm, p, blk, model)))
+# Each step is a jitted call of its own, so that XLA fuses no add or
+# divide into a neighbouring kernel, where it could round differently.
+@partial(jax.jit, donate_argnums=0)
+def accumulate(acc, g):
+    """``acc + g`` leaf by leaf, into ``acc``'s buffers."""
+    return jax.tree.map(jnp.add, acc, g)
 
-    @jax.jit
+
+@partial(jax.jit, donate_argnums=0)
+def mean_over(acc, ntok):
+    """``acc / ntok`` leaf by leaf, into ``acc``'s buffers."""
+    return jax.tree.map(lambda g: g / ntok, acc)
+
+
+def make_adamw(opt: dict, stored) -> Callable:
+    """The jitted AdamW step ``(p, g, m, v, step) -> (p, m, v)``: clip by
+    the global norm, moments, bias correction, decoupled decay on rank
+    >= 2 leaves, ``store`` in each leaf's dtype of ``stored``.  ``p``,
+    ``m`` and ``v`` are donated and the results written into their
+    buffers; ``g`` has no result to go into, and its caller drops it."""
+    @partial(jax.jit, donate_argnums=(0, 2, 3))
     def adamw(p, g, m, v, step):
         gnorm = jnp.sqrt(sum(jnp.sum(x * x) for x in jax.tree.leaves(g)))
         clip = jnp.minimum(1.0, opt["grad_clip"] / jnp.maximum(gnorm, 1e-12))
@@ -203,13 +227,37 @@ def train3(ref, model: dict, opt: dict, layout, key, rows: List[dict],
         out = jax.tree.map(upd, p, g, m, v, stored)
         pick = [jax.tree.map(lambda _, o: o[i], p, out) for i in range(3)]
         return tuple(pick)
+    return adamw
+
+
+def train3(ref, model: dict, opt: dict, layout, key, rows: List[dict],
+           lowp: Optional[str] = None, block_rows: int = 1) -> Readings:
+    """Three AdamW steps of the reference ``ref`` from the weights that
+    ``init(key, layout)`` makes, over ``rows[k]`` (the batch of step k+1:
+    a dict of host arrays, one row per sequence).  ``lowp`` runs the
+    control: operands and stored parameters rounded to that dtype.
+
+    The device holds at most ``STATE_BYTES_PER_PARAM`` bytes a parameter
+    besides one block's activations: the start weights stay on the host,
+    each block's gradient is folded into one donated accumulator, and m
+    and v wait on the host while the gradients are taken (else they and
+    a block's whole gradient would be 20 bytes), coming back for the
+    update, which writes into their buffers."""
+    mm = make_mm(lowp)
+    stored = jax.tree.map(lambda leaf: leaf.dtype, layout,
+                          is_leaf=lambda x: isinstance(x, Leaf))
+    if lowp is not None:
+        stored = jax.tree.map(lambda d: LOWER[d], stored)
+
+    grad_block = jax.jit(jax.value_and_grad(
+        lambda p, blk: ref.loss_sum(mm, p, blk, model)))
+    adamw = make_adamw(opt, stored)
 
     with jax.default_matmul_precision("highest"):
         params = jax.tree.map(lambda x, d: store(x.astype(jnp.float32), d),
                               init(key, layout), stored)
-        p0 = params
-        m = jax.tree.map(jnp.zeros_like, params)
-        v = jax.tree.map(jnp.zeros_like, params)
+        p0 = jax.device_get(jax.tree.leaves(params))
+        moments = None            # (m, v) on the host between updates
         losses, grad_norms, update1 = [], [], []
         for k, batch in enumerate(rows):
             n = next(iter(batch.values())).shape[0]
@@ -219,17 +267,27 @@ def train3(ref, model: dict, opt: dict, layout, key, rows: List[dict],
                        for name, a in batch.items()}
                 ls, g = grad_block(params, blk)
                 total = total + ls
-                grads = jax.tree.map(jnp.add, grads, g)
+                # Wait for the sum: the next block's gradient is then
+                # allocated after this one is freed, not beside it.
+                grads = jax.block_until_ready(accumulate(grads, g))
+                del g
             ntok = n * batch["labels"].shape[1]
-            grads = jax.tree.map(lambda g: g / ntok, grads)
+            grads = mean_over(grads, ntok)
             losses.append(float(total) / ntok)
             if k == 0:
                 grad_norms = leaf_norms(grads)
+                m = jax.tree.map(jnp.zeros_like, params)
+                v = jax.tree.map(jnp.zeros_like, params)
+            else:
+                m, v = jax.device_put(moments)
             params, m, v = adamw(params, grads, m, v, float(k + 1))
+            del grads
             if k == 0:
-                update1 = leaf_norms(jax.tree.map(jnp.subtract, params, p0))
-        update = jax.tree.map(jnp.subtract, params, p0)
-        return Readings(losses, grad_norms, update1, leaf_norms(update))
+                update1 = change_norms(params, p0)
+            moments = jax.device_get((m, v)) if k + 1 < len(rows) else None
+            del m, v
+        return Readings(losses, grad_norms, update1,
+                        change_norms(params, p0))
 
 
 def gaps(prog: Readings, ref: Readings) -> Dict[str, float]:

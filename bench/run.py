@@ -182,6 +182,7 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
     from bench import devtrace as tr
     from bench import layers
     from bench.job import Job
+    from bench.reference import common as C
 
     ref = load_module(root / "bench" / "reference"
                       / f"{config['reference']}.py")
@@ -219,6 +220,8 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
     t_ref = time.perf_counter()
     gaps, want = first_step_gaps(job, prog, ref, first)
     t_done = time.perf_counter()
+    ref_peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                 for d in devs]
     numbers = gaps["program"]
     numbers.update(ingest_mismatches=ingest_bad, ckpt_mismatches=ckpt_bad,
                    des_mismatches=des_bad)
@@ -276,6 +279,11 @@ def run(argv=None, root: Path = ROOT, require_chip: bool = True,
           file=err)
     print(f"bench: program losses {prog.losses!r}, reference "
           f"{want.losses!r}", file=err)
+    n_params = C.param_count(job.layout)
+    print(f"bench: device peak after the reference {ref_peaks!r} bytes, "
+          f"after the window {peaks!r}; reference state budget "
+          f"{C.STATE_BYTES_PER_PARAM} x {n_params} parameters = "
+          f"{C.STATE_BYTES_PER_PARAM * n_params} bytes", file=err)
     for k, c in checks.items():
         print(f"check {k} {c['value']!r} limit {c['limit']!r}", file=err)
     return result

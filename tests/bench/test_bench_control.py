@@ -13,6 +13,7 @@ between chips to leave out.
 """
 
 import json
+import sys
 
 import jax
 import numpy as np
@@ -46,6 +47,24 @@ def test_control_fails_a_limit(name, tiny_root):
     sound, control = gaps["program"], gaps["control"]
     assert all(sound[k] <= limits[k] for k in limits), sound
     assert any(control[k] > limits[k] for k in limits), control
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_study_reads_the_control_and_the_half_batch(name, tiny_root, capsys,
+                                                    monkeypatch):
+    """``bench/study.py``, which sets the limits, runs its stand-ins
+    through the same reference training; each fails a limit."""
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    study = R.load_module(tiny_root / "bench" / "study.py")
+    assert study.main(["--config", name, "--seeds", "1"]) == 0
+    seed, summary = [json.loads(x)
+                     for x in capsys.readouterr().out.splitlines()]
+    limits = json.loads((tiny_root / "bench" / "configs" / f"{name}.json")
+                        .read_text())["limits"]
+    assert summary["lower"] == seed["program"]
+    assert all(seed["program"][k] <= limits[k] for k in limits), seed
+    for stand_in in ("control", "half_batch"):
+        assert any(seed[stand_in][k] > limits[k] for k in limits), seed
 
 
 def _state_unchanged(monkeypatch):

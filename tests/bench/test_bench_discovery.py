@@ -3,10 +3,11 @@ files of their own, and the harness finds them by name."""
 
 import json
 import re
+import shutil
 from types import SimpleNamespace
 
 import pytest
-from bench_tiny import tiny_copy
+from bench_tiny import ROOT, TINY_MODEL, tiny_copy
 
 from bench import run as R
 
@@ -136,4 +137,39 @@ def test_an_untraced_run_records_nothing_and_takes_no_hlo(
     root = tiny_copy(tmp_path)
     res = R.run(["--workload", "gpt2-small-commit.ckpt-restart", "--seed",
                  "4", "--seconds", "1"], root=root, require_chip=False)
+    assert res["correct"] is True, res["checks"]
+
+
+def test_a_configuration_brings_its_own_cpu_size(tmp_path):
+    """A configuration's ``tiny`` object is applied after ``TINY_MODEL``:
+    it sets a width that ``TINY_MODEL`` sets otherwise and a key that it
+    does not know, and the cell runs ``correct`` at that size."""
+    src = tmp_path / "src"
+    src.mkdir()
+    shutil.copy(ROOT / "BENCHMARK.json", src / "BENCHMARK.json")
+    shutil.copytree(ROOT / "bench", src / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    cfg = json.loads((src / "bench" / "configs" / "gpt2-small-commit.json")
+                     .read_text())
+    cfg.update(name="throwaway-lm", tiny={"d_ff": 48, "rope_theta": 500.0})
+    (src / "bench" / "configs" / "throwaway-lm.json").write_text(
+        json.dumps(cfg))
+    manifest = json.loads((src / "BENCHMARK.json").read_text())
+    manifest["configs"].append(
+        {"name": "throwaway-lm", "source": "https://example.org/lm",
+         "file": "bench/configs/throwaway-lm.json", "reduced": [],
+         "why": "a test"})
+    manifest["workloads"].append(
+        {"name": "throwaway-lm.train", "config": "throwaway-lm",
+         "traffic": "train", "chips": 1, "why": "a test"})
+    (src / "BENCHMARK.json").write_text(json.dumps(manifest))
+
+    (tmp_path / "tiny").mkdir()
+    root = tiny_copy(tmp_path / "tiny", src)
+    model = json.loads((root / "bench" / "configs" / "throwaway-lm.json")
+                       .read_text())["model"]
+    assert model["d_ff"] == 48 and model["rope_theta"] == 500.0
+    assert model["d_model"] == TINY_MODEL["d_model"]
+    res = R.run(["--workload", "throwaway-lm.train", "--seed", "6",
+                 "--seconds", "1"], root=root, require_chip=False)
     assert res["correct"] is True, res["checks"]
