@@ -3,8 +3,9 @@
 Every op has (a) a memory-efficient pure-jnp implementation that lowers on
 any backend — this is what the multi-pod dry-run compiles — and (b) a
 Pallas TPU kernel (``impl="pallas"``) validated in interpret mode against
-:mod:`repro.kernels.ref`.  Production TPU deployments flip the impl flag;
-``interpret=True`` runs the kernel body on the CPU instead of the chip.
+:mod:`repro.kernels.ref`.  ``flash_attention`` takes its fused kernel by
+default where the call runs on a TPU with shapes the kernel supports;
+``interpret=True`` runs a kernel body on the CPU instead of the chip.
 """
 
 from __future__ import annotations
@@ -21,11 +22,12 @@ _NEG_INF = -1e30
 
 
 # ===========================================================================
-# Flash attention (chunked online-softmax; the dry-run / CPU path)
+# Flash attention (fused Pallas kernel on the TPU; chunked online softmax
+# everywhere else: the dry-run / CPU path)
 # ===========================================================================
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
-                    scale: Optional[float] = None, impl: str = "chunked",
+                    scale: Optional[float] = None, impl: Optional[str] = None,
                     q_chunk: int = 512, kv_chunk: int = 1024,
                     interpret: bool = False) -> jax.Array:
     """Memory-efficient attention.  q: (B,T,H,D); k,v: (B,S,K,D), H%K==0.
@@ -33,8 +35,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     The last query position is aligned with the last key position (so a
     suffix of new tokens against a longer KV prefix works for prefill).
     ``window > 0`` restricts attention to the ``window`` most recent keys
-    (recurrentgemma local attention).
+    (recurrentgemma local attention).  ``impl=None`` picks the fused
+    kernel where :func:`attention_impl` says so, else ``"chunked"``.
     """
+    if impl is None:
+        impl = attention_impl(jax.default_backend(), q.shape, k.shape,
+                              v.shape, _mesh_devices(), q.dtype,
+                              jax.config.jax_default_matmul_precision)
     if impl == "ref":
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   scale=scale)
@@ -44,6 +51,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                           window=window, scale=scale,
                                           interpret=interpret)
     return _flash_chunked(q, k, v, causal, window, scale, q_chunk, kv_chunk)
+
+
+def attention_impl(platform: str, q_shape, k_shape, v_shape,
+                   mesh_devices: int = 1, dtype="bfloat16",
+                   precision: Optional[str] = None) -> str:
+    """``"pallas"`` (the fused kernel and its backward) where the call runs
+    on a TPU with shapes the kernel supports, else ``"chunked"``: decided
+    by what the call can observe, the platform, its mesh, the shapes and,
+    for f32 inputs, the matmul precision the call is traced under."""
+    from repro.kernels import flash_attention as _fa
+    return ("pallas" if _fa.supports(platform, q_shape, k_shape, v_shape,
+                                     mesh_devices, dtype, precision)
+            else "chunked")
+
+
+def _mesh_devices() -> int:
+    """Devices of the mesh the call is traced under (1 without one)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else mesh.size
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.quantize import quantize_pallas
@@ -66,6 +67,267 @@ def test_flash_pallas_block_sweep():
                                      block_q=bq, block_k=bk, interpret=True)
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5,
                                    err_msg=f"block ({bq},{bk})")
+
+
+FUSED_CASES = [
+    # B, T, S, H, K, D, causal, window, block_q, block_k
+    (2, 32, 32, 4, 4, 16, True, 0, 8, 8),      # causal: blocks skipped
+    (1, 32, 32, 4, 2, 16, False, 0, 8, 8),     # bidirectional (encoder)
+    (1, 16, 150, 4, 4, 16, False, 0, 8, 32),   # cross, 128 x 1,500 scaled
+    (1, 20, 20, 2, 2, 8, True, 0, 8, 8),       # ragged: not a block multiple
+    (1, 24, 24, 6, 2, 8, True, 0, 8, 8),       # GQA rep=3
+    (1, 40, 40, 4, 1, 8, True, 12, 8, 8),      # local window, MQA
+    (2, 8, 24, 4, 2, 8, True, 0, 8, 8),        # suffix queries (prefill)
+    (1, 20, 20, 2, 2, 8, True, 0, None, None),  # blocks from the shapes
+    (1, 16, 16, 2, 2, 192, True, 0, 8, 8),     # head_dim not a lane multiple
+]
+
+
+def _fused_and_ref(case, dtype, precision=None):
+    """Output and vjp of the fused kernel, traced under the matmul
+    ``precision`` (the default when None), and of the oracle."""
+    B, T, S, H, K, D, causal, window, bq, bk = case
+    q, k, v = _qkv(jax.random.PRNGKey(10), B, T, S, H, K, D, dtype)
+    do = jax.random.normal(jax.random.PRNGKey(11), q.shape).astype(dtype)
+
+    def fused(q, k, v):
+        with jax.default_matmul_precision(precision):
+            return flash_attention_pallas(q, k, v, causal=causal,
+                                          window=window, block_q=bq,
+                                          block_k=bk, interpret=True)
+
+    def oracle(q, k, v):
+        return ref.attention_ref(q, k, v, causal=causal, window=window)
+
+    out = []
+    for f in (fused, oracle):
+        o, vjp = jax.vjp(f, q, k, v)
+        out.append((o, *vjp(do)))
+    return out
+
+
+def _rel(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+@pytest.mark.parametrize("case", FUSED_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_attention_and_grads_vs_ref(case, dtype):
+    """The fused kernel's output and its backward's dq, dk, dv against
+    the oracle and its autodiff gradients."""
+    got, want = _fused_and_ref(case, dtype)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.dtype == w.dtype, name
+        assert _rel(g, w) <= tol, (name, _rel(g, w))
+
+
+@pytest.mark.parametrize("case", FUSED_CASES[:3])
+def test_fused_attention_bf16_operands(case):
+    """f32 inputs under a one-pass matmul precision, as the TPU's default
+    runs an f32 dot: the dots take bf16 operands, the rest stays f32 and
+    within bf16 rounding of the oracle."""
+    got, want = _fused_and_ref(case, jnp.float32, "bfloat16")
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.dtype == jnp.float32, name
+        assert 1e-4 < _rel(g, w) <= 2e-2, (name, _rel(g, w))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_dq_has_no_bias_along_the_mean_key(dtype):
+    """Keys with a large common part: dq stays within bf16 rounding of
+    the oracle on the bf16 operands.  A softmax term di = rowsum(dO * O)
+    taken from a dO or an O other than the kernels' own (an f32 dO, a
+    bf16 O) leaves a bias along the mean key, about 5x this error."""
+    B, T, S, H, D = 1, 128, 128, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(15), 5)
+    q = jax.random.normal(ks[0], (B, T, H, D)).astype(dtype)
+    k = (jax.random.normal(ks[1], (B, S, H, D))
+         + 4 * jax.random.normal(ks[4], (1, 1, H, D))).astype(dtype)
+    v = (jax.random.normal(ks[2], (B, S, H, D)) + 1).astype(dtype)
+    do = jax.random.normal(ks[3], (B, T, H, D)).astype(dtype)
+
+    def rounded(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    _, vjp = jax.vjp(lambda q: ref.attention_ref(
+        q, rounded(k), rounded(v), causal=False), rounded(q))
+    want = vjp(rounded(do))[0]
+    with jax.default_matmul_precision("bfloat16"):
+        _, vjp = jax.vjp(lambda q: flash_attention_pallas(
+            q, k, v, causal=False, block_q=32, block_k=32, interpret=True), q)
+        got = np.asarray(vjp(do)[0], np.float32)
+    err = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert err < 2e-2, err
+
+
+@pytest.mark.parametrize("case", [FUSED_CASES[0], FUSED_CASES[2],
+                                  FUSED_CASES[5], FUSED_CASES[6]])
+def test_fused_attention_log_sum_exp_residual(case):
+    """The forward's one residual besides its output: each query row's
+    log-sum-exp of its visible scaled scores, f32."""
+    B, T, S, H, K, D, causal, window, bq, bk = case
+    q, k, v = _qkv(jax.random.PRNGKey(12), B, T, S, H, K, D, jnp.float32)
+    _, bq = fa._tile(T, bq)
+    _, bk = fa._tile(S, bk, fa.MAX_KV_BLOCK)
+    g = fa._Geometry(T=T, S=S, bq=bq, bk=bk, causal=causal, window=window,
+                     scale=D ** -0.5, rep=H // K, interpret=True,
+                     dtypes=("float32",) * 3, operand="float32")
+    _, (*_, lse) = fa._attend_fwd(q, k, v, g)
+    kr = jnp.repeat(k, H // K, axis=2)
+    s = jnp.einsum("bthd,bshd->bhts", q, kr) * D ** -0.5
+    qpos = jnp.arange(T)[:, None] + (S - T)
+    kpos = jnp.arange(S)[None, :]
+    mask = jnp.ones((T, S), bool)
+    if causal:
+        mask &= kpos <= qpos
+    if window:
+        mask &= kpos > qpos - window
+    want = jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+    assert lse.dtype == jnp.float32
+    assert lse.shape == (B * H, 1, g.Tp)
+    np.testing.assert_allclose(lse[:, 0, :T].reshape(B, H, T), want,
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", [FUSED_CASES[0], FUSED_CASES[4],
+                                  FUSED_CASES[5], FUSED_CASES[6]])
+def test_skipped_blocks_change_nothing(case, monkeypatch):
+    """Visiting every block, masked, with no index clamped, gives bitwise
+    the output and gradients of the run that skips hidden blocks."""
+    skipping = _fused_and_ref(case, jnp.float32)[0]
+    monkeypatch.setattr(fa._Geometry, "needed",
+                        lambda self, qi, kj: kj * self.bk < self.S)
+    monkeypatch.setattr(fa._Geometry, "kv_block", lambda self, qi, kj: kj)
+    monkeypatch.setattr(fa._Geometry, "q_block", lambda self, kj, qi: qi)
+    visiting = _fused_and_ref(case, jnp.float32)[0]
+    for name, a, b in zip(("o", "dq", "dk", "dv"), skipping, visiting):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("case", [FUSED_CASES[0], FUSED_CASES[3],
+                                  FUSED_CASES[5], FUSED_CASES[6]])
+def test_index_maps_fetch_each_needed_block_once(case):
+    """Along the reduction axis a block index changes only onto a needed
+    block: a skipped step repeats its neighbour's index, so the pipeline
+    issues no DMA for it, and every needed block keeps its own."""
+    B, T, S, H, K, D, causal, window, bq, bk = case
+    g = fa._Geometry(T=T, S=S, bq=bq, bk=bk, causal=causal, window=window,
+                     scale=1.0, rep=H // K, interpret=True,
+                     dtypes=("float32",) * 3, operand="float32")
+
+    def fetches(indices):
+        return sum(1 for i, x in enumerate(indices)
+                   if i == 0 or x != indices[i - 1])
+
+    for qi in range(g.nq):
+        need = [kj for kj in range(g.nk) if bool(g.needed(qi, kj))]
+        idx = [int(g.kv_block(qi, kj)) for kj in range(g.nk)]
+        assert [idx[kj] for kj in need] == need
+        assert fetches(idx) == max(len(need), 1)
+    for kj in range(g.nk):
+        need = [qi for qi in range(g.nq) if bool(g.needed(qi, kj))]
+        idx = [int(g.q_block(kj, qi)) for qi in range(g.nq)]
+        assert [idx[qi] for qi in need] == need
+        assert fetches(idx) == max(len(need), 1)
+    if causal and T == S:
+        assert sum(bool(g.needed(qi, kj)) for qi in range(g.nq)
+                   for kj in range(g.nk)) < g.nq * g.nk
+
+
+def test_attention_dispatch_by_platform_and_shapes():
+    """The fused kernel where the call runs on a TPU, alone in its mesh,
+    with shapes it supports; the chunked path everywhere else."""
+    gpt2 = (16, 1024, 12, 64)
+    enc, cross_q = (8, 1500, 12, 64), (8, 128, 12, 64)
+    assert ops.attention_impl("tpu", gpt2, gpt2, gpt2) == "pallas"
+    assert ops.attention_impl("tpu", cross_q, enc, enc) == "pallas"
+    assert ops.attention_impl("tpu", (1, 64, 8, 128), (1, 64, 2, 128),
+                              (1, 64, 2, 128)) == "pallas"       # GQA
+    assert ops.attention_impl("cpu", gpt2, gpt2, gpt2) == "chunked"
+    assert ops.attention_impl("gpu", gpt2, gpt2, gpt2) == "chunked"
+    assert ops.attention_impl("tpu", gpt2, gpt2, gpt2,
+                              mesh_devices=4) == "chunked"
+    mla_qk, mla_v = (1, 64, 16, 192), (1, 64, 16, 128)
+    assert ops.attention_impl("tpu", mla_qk, mla_qk, mla_v) == "chunked"
+    wide = (1, 64, 4, 512)
+    assert ops.attention_impl("tpu", wide, wide, wide) == "chunked"
+    assert ops.attention_impl("tpu", (1, 64, 6, 64), (1, 64, 4, 64),
+                              (1, 64, 4, 64)) == "chunked"
+    # f32 inputs take the fused kernel's one bf16 pass only where the
+    # matmul precision asks for no more; bf16 inputs under any precision.
+    for precision in (None, "default", "bfloat16"):
+        assert ops.attention_impl("tpu", gpt2, gpt2, gpt2, 1, jnp.float32,
+                                  precision) == "pallas"
+    for precision in ("highest", "float32", "high", "tensorfloat32"):
+        assert ops.attention_impl("tpu", gpt2, gpt2, gpt2, 1, jnp.float32,
+                                  precision) == "chunked"
+        assert ops.attention_impl("tpu", gpt2, gpt2, gpt2, 1, jnp.bfloat16,
+                                  precision) == "pallas"
+
+
+def test_dot_operands_follow_the_matmul_precision():
+    """f32 inputs take bf16 dot operands where XLA gives an f32 dot one
+    pass: the default precision on the TPU (not interpreted), a one-pass
+    setting anywhere; f32 under a higher setting or interpreted on the
+    CPU's default.  bf16 inputs stay bf16."""
+    assert fa._operand(jnp.float32, interpret=False) == "bfloat16"
+    assert fa._operand(jnp.float32, interpret=True) == "float32"
+    assert fa._operand(jnp.bfloat16, interpret=False) == "bfloat16"
+    with jax.default_matmul_precision("highest"):
+        for interpret in (False, True):
+            assert fa._operand(jnp.float32, interpret) == "float32"
+            assert fa._operand(jnp.bfloat16, interpret) == "bfloat16"
+    with jax.default_matmul_precision("bfloat16"):
+        for interpret in (False, True):
+            assert fa._operand(jnp.float32, interpret) == "bfloat16"
+
+
+@pytest.mark.parametrize("case", ATTN_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_default_attention_on_cpu_is_the_chunked_path(case, dtype):
+    """On the CPU the default stays the chunked path: bitwise its output
+    and gradients."""
+    B, T, S, H, K, D, causal, window = case
+    q, k, v = _qkv(jax.random.PRNGKey(13), B, T, S, H, K, D, dtype)
+    assert ops.attention_impl(jax.default_backend(), q.shape, k.shape,
+                              v.shape) == "chunked"
+    outs = []
+    for impl in (None, "chunked"):
+        def f(q, k, v, impl=impl):
+            return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                       impl=impl)
+        o, vjp = jax.vjp(f, q, k, v)
+        outs.append((o, *vjp(jnp.ones_like(o))))
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_backward_kernels_run_under_the_attention_scope():
+    """Every op of the backward kernels carries the caller's
+    ``attention`` scope and the transpose's ``transpose(`` in its HLO
+    name stack, as ``bench/layers.step_scopes`` reads them."""
+    from bench import layers
+
+    q, k, v = _qkv(jax.random.PRNGKey(14), 1, 16, 16, 2, 2, 8, jnp.float32)
+
+    def loss(q, k, v):
+        with jax.named_scope("attention"):
+            o = flash_attention_pallas(q, k, v, causal=True, block_q=8,
+                                       block_k=8, interpret=True)
+        return jnp.sum(o * o)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).compile().as_text()
+    scopes = layers.hlo_scopes(text)
+    for kernel in ("flash_attention_dq", "flash_attention_dkv"):
+        ops_of = [s for s in scopes.values() if f"/{kernel}/" in s]
+        assert len(ops_of) > 10, kernel
+        for s in ops_of:
+            assert "attention" in layers._scope_words(s), s
+            assert layers.step_part(s) == "backward", s
 
 
 SSM_CASES = [(1, 8, 4, 2), (2, 16, 8, 4), (1, 24, 6, 3)]  # B, T, I, N
